@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.serving import trace
 from repro.serving.batch import RaggedBatch, padded_pow2
 from repro.serving.blocks import KVCacheManager
 from repro.serving.scheduler import (Request, RequestState, Scheduler,
@@ -526,46 +527,56 @@ class PagedDecodeEngine:
         """The rectangular (n_slots, chunk_width) step: every lane is
         padded to the widest scheduled chunk.  Returns ``greedy(req, j)``,
         the step's argmax token at row ``j`` of ``req``'s chunk."""
-        sched_ids = {r.request_id for r in decision.scheduled}
-        width = padded_pow2(max(
-            [decision.num_scheduled[r.request_id]
-             for r in decision.scheduled] or [1]))
-        tokens = np.zeros((self.n_slots, width), np.int32)
-        pos = np.zeros((self.n_slots,), np.int32)
-        q_lens = np.zeros((self.n_slots,), np.int32)
-        tables = np.zeros((self.n_slots, self.max_blocks), np.int32)
-        # paused (budget-deferred) lanes keep q_lens = 0: their writes are
-        # routed to the null block and their logits ignored — harmless
-        for r in self.scheduler.running:
-            pos[r.lane] = r.cursor
-            tables[r.lane] = self.kv.padded_table(r.request_id)
-            if r.request_id in sched_ids:
-                n = decision.num_scheduled[r.request_id]
-                q_lens[r.lane] = n
-                tokens[r.lane, :n] = decision.segment_tokens(r)
-        self.cache["block_tables"] = self._put(tables)
-        self.cache["pos"] = self._put(pos)
-        self.cache["q_lens"] = self._put(q_lens)
-        dev_tokens = self._put(tokens)
-        if self.mesh is not None and self._collectives_per_step is None:
-            self._collectives_per_step = self._count_collectives(dev_tokens)
-        logits, self.cache = self._step(self.params, self.cache, dev_tokens)
+        with trace.span(trace.PREPARE):
+            sched_ids = {r.request_id for r in decision.scheduled}
+            width = padded_pow2(max(
+                [decision.num_scheduled[r.request_id]
+                 for r in decision.scheduled] or [1]))
+            tokens = np.zeros((self.n_slots, width), np.int32)
+            pos = np.zeros((self.n_slots,), np.int32)
+            q_lens = np.zeros((self.n_slots,), np.int32)
+            tables = np.zeros((self.n_slots, self.max_blocks), np.int32)
+            # paused (budget-deferred) lanes keep q_lens = 0: their writes
+            # are routed to the null block and their logits ignored
+            for r in self.scheduler.running:
+                pos[r.lane] = r.cursor
+                tables[r.lane] = self.kv.padded_table(r.request_id)
+                if r.request_id in sched_ids:
+                    n = decision.num_scheduled[r.request_id]
+                    q_lens[r.lane] = n
+                    tokens[r.lane, :n] = decision.segment_tokens(r)
+            self.cache["block_tables"] = self._put(tables)
+            self.cache["pos"] = self._put(pos)
+            self.cache["q_lens"] = self._put(q_lens)
+            dev_tokens = self._put(tokens)
+            if self.mesh is not None and self._collectives_per_step is None:
+                self._collectives_per_step = self._count_collectives(
+                    dev_tokens)
+        real = int(q_lens.sum())
+        with trace.span(trace.DISPATCH, tokens=self.n_slots * width,
+                        real_tokens=real,
+                        prefill_tokens=decision.n_prefill_tokens,
+                        decode_lanes=decision.n_decode):
+            logits, self.cache = self._step(self.params, self.cache,
+                                            dev_tokens)
         self._last_tokens = dev_tokens
         self.collective_ops += self._collectives_per_step or 0
-        self.scheduled_tokens += int(q_lens.sum())
+        self.scheduled_tokens += real
         self.padded_tokens += self.n_slots * width
-        if decision.drafts:
-            # speculative verification reads every row of a draft segment
-            # — but still only those: gather them (plus each lane's last
-            # row) before the argmax instead of reducing all (slots, C)
-            flat = logits.reshape(self.n_slots * width, -1)
-            return self._gather_greedy(
-                decision, flat, lambda r: r.lane * width)
-        # only each lane's last real chunk row can emit — gather those
-        # (n_slots, V) rows before the argmax instead of reducing all C
-        last = jnp.asarray(np.maximum(q_lens - 1, 0))
-        lane_tok = np.asarray(jnp.argmax(
-            logits[jnp.arange(self.n_slots), last], axis=-1))   # (slots,)
+        with trace.span(trace.SAMPLE):
+            if decision.drafts:
+                # speculative verification reads every row of a draft
+                # segment — but still only those: gather them (plus each
+                # lane's last row) before the argmax instead of reducing
+                # all (slots, C)
+                flat = logits.reshape(self.n_slots * width, -1)
+                return self._gather_greedy(
+                    decision, flat, lambda r: r.lane * width)
+            # only each lane's last real chunk row can emit — gather those
+            # (n_slots, V) rows before the argmax instead of reducing all C
+            last = jnp.asarray(np.maximum(q_lens - 1, 0))
+            lane_tok = np.asarray(jnp.argmax(
+                logits[jnp.arange(self.n_slots), last], axis=-1))  # (slots,)
         return lambda r, j: int(lane_tok[r.lane])
 
     def _gather_greedy(self, decision: StepDecision, flat_logits,
@@ -593,42 +604,51 @@ class PagedDecodeEngine:
         token count, ~sum(q_len) instead of lanes * max(q_len).  Returns
         ``greedy(req, j)``, the step's argmax token at row ``j`` of
         ``req``'s segment."""
-        batch = RaggedBatch.build(decision, self.kv, self.n_slots,
-                                  self.block_size,
-                                  cap=self.scheduler._budget())
-        tables = np.zeros((self.n_slots, self.max_blocks), np.int32)
-        for r in self.scheduler.running:
-            tables[r.lane] = self.kv.padded_table(r.request_id)
-        self.cache["block_tables"] = self._put(tables)
-        self.cache["token_lane"] = self._put(batch.token_lane)
-        self.cache["token_pos"] = self._put(batch.token_pos)
-        self.cache["slot_mapping"] = self._put(batch.slot_mapping)
-        if self.tiled:
-            # segment-tile the stream: tile capacity is a pure function of
-            # the pow2 bucket (windows + n_slots), so the jitted step still
-            # retraces per bucket only
-            tiles = batch.tiles(self.n_slots, self.tile)
-            self.cache["tile_meta"] = self._put(tiles.meta)
-            self.cache["row_tile"] = self._put(tiles.row_tile)
-        dev_tokens = self._put(batch.tokens)
-        if self.mesh is not None and self._collectives_per_step is None:
-            self._collectives_per_step = self._count_collectives(dev_tokens)
-        logits, self.cache = self._step(self.params, self.cache, dev_tokens)
+        with trace.span(trace.PREPARE):
+            batch = RaggedBatch.build(decision, self.kv, self.n_slots,
+                                      self.block_size,
+                                      cap=self.scheduler._budget())
+            tables = np.zeros((self.n_slots, self.max_blocks), np.int32)
+            for r in self.scheduler.running:
+                tables[r.lane] = self.kv.padded_table(r.request_id)
+            self.cache["block_tables"] = self._put(tables)
+            self.cache["token_lane"] = self._put(batch.token_lane)
+            self.cache["token_pos"] = self._put(batch.token_pos)
+            self.cache["slot_mapping"] = self._put(batch.slot_mapping)
+            if self.tiled:
+                # segment-tile the stream: tile capacity is a pure function
+                # of the pow2 bucket (windows + n_slots), so the jitted step
+                # still retraces per bucket only
+                tiles = batch.tiles(self.n_slots, self.tile)
+                self.cache["tile_meta"] = self._put(tiles.meta)
+                self.cache["row_tile"] = self._put(tiles.row_tile)
+            dev_tokens = self._put(batch.tokens)
+            if self.mesh is not None and self._collectives_per_step is None:
+                self._collectives_per_step = self._count_collectives(
+                    dev_tokens)
+        with trace.span(trace.DISPATCH, tokens=batch.padded_tokens,
+                        real_tokens=batch.total_tokens,
+                        prefill_tokens=decision.n_prefill_tokens,
+                        decode_lanes=decision.n_decode):
+            logits, self.cache = self._step(self.params, self.cache,
+                                            dev_tokens)
         self._last_tokens = dev_tokens
         self.collective_ops += self._collectives_per_step or 0
         self.scheduled_tokens += batch.total_tokens
         self.padded_tokens += batch.padded_tokens
-        if decision.drafts:
-            # speculative verification reads every row of a draft segment
-            # — but still only those: gather them (plus each lane's last
-            # row) before the argmax instead of reducing all T
-            starts = batch.q_starts
-            return self._gather_greedy(decision, logits,
-                                       lambda r: starts[r.request_id])
-        # only each lane's final segment row can emit — gather those
-        # (n_slots, V) rows before the argmax instead of reducing all T
-        lane_tok = np.asarray(jnp.argmax(
-            logits[jnp.asarray(batch.last_row)], axis=-1))      # (slots,)
+        with trace.span(trace.SAMPLE):
+            if decision.drafts:
+                # speculative verification reads every row of a draft
+                # segment — but still only those: gather them (plus each
+                # lane's last row) before the argmax instead of reducing
+                # all T
+                starts = batch.q_starts
+                return self._gather_greedy(decision, logits,
+                                           lambda r: starts[r.request_id])
+            # only each lane's final segment row can emit — gather those
+            # (n_slots, V) rows before the argmax instead of reducing all T
+            lane_tok = np.asarray(jnp.argmax(
+                logits[jnp.asarray(batch.last_row)], axis=-1))  # (slots,)
         return lambda r, j: int(lane_tok[r.lane])
 
     def step(self) -> StepDecision:
@@ -642,8 +662,29 @@ class PagedDecodeEngine:
         KV cache is rewound past the rejected draft slots so the next
         step's appends land where the accepted sequence actually ends."""
         t0 = time.perf_counter()
-        emitted = 0
-        decision = self.scheduler.schedule()
+        with trace.step_span(self.steps):
+            decision = self.scheduler.schedule()
+            with trace.span(trace.KV_COPY):
+                self._copy_blocks()
+            greedy = (self._run_ragged(decision) if self.ragged
+                      else self._run_rect(decision))
+            self.steps += 1
+            with trace.span(trace.EMIT):
+                emitted = self._accept(decision, greedy)
+                shed = self.scheduler.take_shed()
+                if shed:
+                    self._finished.extend(shed)
+                    self.shed += len(shed)
+                # feed the SLO admission loop: real wall seconds per decode
+                # token (consistent with SimClock.measure, which also
+                # charges real time)
+                self.scheduler.observe_step(time.perf_counter() - t0,
+                                            emitted)
+        return decision
+
+    def _copy_blocks(self) -> None:
+        """The block copies a step's KV writes must follow: the queued
+        host->device swap-ins, then the copy-on-write copies."""
         # host->device swap-ins FIRST: a swapped-in block must hold its
         # payload before a CoW copy reads it (a fully-matched prompt can
         # fork a block this very admission just swapped in) and before
@@ -671,10 +712,11 @@ class PagedDecodeEngine:
                                    self._put(dst))
             self.cow_block_copies += len(copies)
 
-        greedy = (self._run_ragged(decision) if self.ragged
-                  else self._run_rect(decision))
-        self.steps += 1
-
+    def _accept(self, decision: StepDecision, greedy) -> int:
+        """Accept each scheduled request's tokens from the step's argmax
+        ``greedy``, emit them, and retire or rewind the request; returns
+        the number of tokens emitted."""
+        emitted = 0
         for r in list(decision.scheduled):
             n = decision.num_scheduled[r.request_id]
             drafts = decision.drafts.get(r.request_id, [])
@@ -720,14 +762,7 @@ class PagedDecodeEngine:
                 # that only held rejected tokens) so the KV watermark
                 # matches the accepted sequence exactly
                 self.kv.rewind(r.request_id, r.cursor)
-        shed = self.scheduler.take_shed()
-        if shed:
-            self._finished.extend(shed)
-            self.shed += len(shed)
-        # feed the SLO admission loop: real wall seconds per decode token
-        # (consistent with SimClock.measure, which also charges real time)
-        self.scheduler.observe_step(time.perf_counter() - t0, emitted)
-        return decision
+        return emitted
 
     def has_work(self) -> bool:
         """True while requests are queued or running (uniform across the
@@ -1366,6 +1401,7 @@ class SlotDecodeEngine:
             if self.active[slot] is None and self.queue:
                 req = self.queue.pop(0)
                 req.begin_run(slot)
+                req.t_admit = self._now()
                 self.active[slot] = req
                 if "slot_positions" in self.cache and "scan" in self.cache:
                     # transformer-family rolling KV (pure cache, no recurrent

@@ -50,6 +50,7 @@ from typing import Callable, Deque, Dict, List, Optional
 
 import numpy as np
 
+from repro.serving import trace
 from repro.serving.blocks import KVCacheManager
 
 
@@ -94,6 +95,7 @@ class Request:
     # --- latency accounting (stamped from the engine's clock: wall time,
     # or the shared SimClock in disaggregated / open-loop runs) ---
     t_submit: float = 0.0
+    t_admit: float = 0.0             # first move into a lane (kept on resume)
     t_first_token: float = 0.0
     t_done: float = 0.0
 
@@ -332,6 +334,8 @@ class Scheduler:
             del self.waiting[idx]
             lane = self.lanes.index(None)
             req.begin_run(lane)
+            if req.n_preemptions == 0:
+                req.t_admit = self.now_fn()
             self.lanes[lane] = req
             self.running.append(req)
             # share the longest cached prefix; cursor skips past it
@@ -373,6 +377,10 @@ class Scheduler:
     def schedule(self) -> StepDecision:
         """Assign this step's per-request token counts under one budget,
         admit, and guarantee a KV slot for every scheduled token."""
+        with trace.span(trace.SCHED):
+            return self._schedule()
+
+    def _schedule(self) -> StepDecision:
         decision = StepDecision(scheduled=[])
         budget_left = self._budget()
         chunk = self._chunk()
@@ -427,7 +435,8 @@ class Scheduler:
                 decision.num_scheduled[r.request_id] = n
                 budget_left -= n
 
-        budget_left = self._admit(budget_left, decision, scheduled)
+        with trace.span(trace.SCHED_ADMIT):
+            budget_left = self._admit(budget_left, decision, scheduled)
 
         # ragged bucket fill: the flat batch is padded to a pow2 total, so
         # extend prefill chunks (beyond chunk_tokens — the per-lane width
@@ -452,6 +461,24 @@ class Scheduler:
                     decision.num_scheduled[r.request_id] = n + extra
                     spare -= extra
 
+        with trace.span(trace.SCHED_RESERVE):
+            self._reserve(decision, scheduled)
+
+        decision.scheduled = scheduled
+        for r in scheduled:
+            n = decision.num_scheduled[r.request_id]
+            if r.is_decode:
+                decision.n_decode += 1
+                decision.n_decode_tokens += n
+                decision.n_draft_tokens += len(
+                    decision.drafts.get(r.request_id, ()))
+            else:
+                decision.n_prefill += 1
+                decision.n_prefill_tokens += n
+        return decision
+
+    def _reserve(self, decision: StepDecision,
+                 scheduled: List[Request]) -> None:
         # guarantee a KV slot for every scheduled token, in priority order;
         # evict from the back (latest admitted) when the pool runs dry —
         # truncating the current chunk instead when the victim would be the
@@ -510,19 +537,6 @@ class Scheduler:
                 drafts = decision.drafts.pop(req.request_id, None)
                 if drafts is not None and k > 1:
                     decision.drafts[req.request_id] = drafts[:k - 1]
-
-        decision.scheduled = scheduled
-        for r in scheduled:
-            n = decision.num_scheduled[r.request_id]
-            if r.is_decode:
-                decision.n_decode += 1
-                decision.n_decode_tokens += n
-                decision.n_draft_tokens += len(
-                    decision.drafts.get(r.request_id, ()))
-            else:
-                decision.n_prefill += 1
-                decision.n_prefill_tokens += n
-        return decision
 
     # ------------------------------------------------------------------
     def finish(self, req: Request) -> None:
