@@ -204,6 +204,17 @@ class TileMap:
     n_tiles: int                       # real tiles (<= capacity)
     tile: int                          # q-window row count (pow2)
 
+    def kv_blocks(self, block_size: int, window: int = 0) -> int:
+        """(tile, logical KV block) pairs the tiled kernel visits: for
+        each real tile, the blocks from the one holding its first row's
+        window start (block 0 without a window) to the one holding its
+        deepest row's position — exactly the blocks holding a key that
+        some row of the tile may see."""
+        lo, hi, pos0 = self.meta[[TILE_LO, TILE_HI, TILE_POS0],
+                                 :self.n_tiles].astype(np.int64)
+        j_lo = np.maximum(pos0 - window + 1, 0) // block_size if window else 0
+        return int(((pos0 + hi - 1 - lo) // block_size - j_lo + 1).sum())
+
 
 def build_tile_map(seg_offsets, seg_lens, seg_lanes, seg_pos0,
                    padded_tokens: int, n_lanes: int, tile: int) -> TileMap:

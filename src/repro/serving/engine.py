@@ -389,6 +389,8 @@ class PagedDecodeEngine:
         # the compiled step actually processed
         self.scheduled_tokens = 0
         self.padded_tokens = 0
+        # (tile, KV block) visits of the tiled attention, per layer
+        self.kv_block_visits = 0
         # speculative-decode accounting: drafted vs accepted draft tokens,
         # and per-verification emitted counts (always >= 1: the bonus)
         self.tokens_drafted = 0
@@ -615,6 +617,7 @@ class PagedDecodeEngine:
             self.cache["token_lane"] = self._put(batch.token_lane)
             self.cache["token_pos"] = self._put(batch.token_pos)
             self.cache["slot_mapping"] = self._put(batch.slot_mapping)
+            tile_args = {}
             if self.tiled:
                 # segment-tile the stream: tile capacity is a pure function
                 # of the pow2 bucket (windows + n_slots), so the jitted step
@@ -622,6 +625,9 @@ class PagedDecodeEngine:
                 tiles = batch.tiles(self.n_slots, self.tile)
                 self.cache["tile_meta"] = self._put(tiles.meta)
                 self.cache["row_tile"] = self._put(tiles.row_tile)
+                tile_args["kv_blocks"] = tiles.kv_blocks(self.block_size,
+                                                         self.window)
+                self.kv_block_visits += tile_args["kv_blocks"]
             dev_tokens = self._put(batch.tokens)
             if self.mesh is not None and self._collectives_per_step is None:
                 self._collectives_per_step = self._count_collectives(
@@ -629,7 +635,7 @@ class PagedDecodeEngine:
         with trace.span(trace.DISPATCH, tokens=batch.padded_tokens,
                         real_tokens=batch.total_tokens,
                         prefill_tokens=decision.n_prefill_tokens,
-                        decode_lanes=decision.n_decode):
+                        decode_lanes=decision.n_decode, **tile_args):
             logits, self.cache = self._step(self.params, self.cache,
                                             dev_tokens)
         self._last_tokens = dev_tokens
@@ -1036,6 +1042,7 @@ class PagedDecodeEngine:
             "tiled": int(self.tiled),
             "padding_efficiency": (self.scheduled_tokens
                                    / max(self.padded_tokens, 1)),
+            "kv_block_visits": self.kv_block_visits,
             "spec": int(self.spec),
             "kv_rewinds": self.kv.rewinds,
             "kv_tokens_rewound": self.kv.tokens_rewound,

@@ -19,7 +19,9 @@ Span                       Covers
 ``serve.engine.prepare``   batch, tables and metadata built and committed
 ``serve.engine.dispatch``  the jitted model step's call; args ``tokens``
                            (the bucket), ``real_tokens``,
-                           ``prefill_tokens``, ``decode_lanes``
+                           ``prefill_tokens``, ``decode_lanes``, and
+                           on the tiled grid ``kv_blocks`` (the
+                           kernel's (tile, KV block) visits a layer)
 ``serve.engine.sample``    row gather, argmax and the pull to the host
 ``serve.engine.emit``      accept, emit, rewind, shed and the SLO update
 *scheduler + KV manager*

@@ -14,7 +14,8 @@ from repro.kernels.ref import (paged_attention_chunk_reference,
                                paged_attention_reference,
                                pool_gather_stats)
 from repro.kernels import ops
-from repro.serving.batch import TILE_HI, TILE_LO, build_tile_map
+from repro.serving.batch import (TILE_HI, TILE_LO, TILE_POS0,
+                                 build_tile_map)
 
 
 def _setup(key, B, Hkv, G, D, num_blocks, bs, max_blocks, ctx, dtype):
@@ -339,7 +340,8 @@ def test_ops_ragged_wrapper_dispatches_to_reference_on_cpu(key):
 
 
 # ---------------------------------------------------------------------------
-# segment-tiled generalization: (q_tile, kv_head, kv_block) grid
+# segment-tiled generalization: a (q_tile) grid walking each tile's live
+# KV blocks
 # ---------------------------------------------------------------------------
 # (tile, segments, T_pad): each segment is (start_pos, n_tokens) for one
 # lane, packed back to back into a flat stream padded to T_pad.  The mixes
@@ -352,6 +354,15 @@ TILED_MIXES = {
     "segments_smaller_than_tile": (16, [(0, 3), (1, 2), (4, 6)], 16),
     "segments_larger_than_tile": (4, [(0, 17), (5, 9)], 32),
     "all_decode": (8, [(2, 1), (5, 1), (9, 1), (0, 1)], 8),
+    # contexts ending exactly on a block edge (8, 12), one token past it
+    # (9, 13), and at max_blocks * bs (32)
+    "block_edges": (4, [(7, 1), (8, 1), (31, 1), (4, 8), (9, 4), (20, 12)],
+                    32),
+    # 2 real tiles in a capacity of 4 windows + 2 lanes
+    "mostly_inert": (16, [(5, 1), (26, 2)], 64),
+    # decodes between prefill chunks, windows straddling every boundary
+    "mixed_straddle": (8, [(10, 1), (0, 11), (22, 1), (4, 6), (17, 1),
+                           (1, 3)], 24),
 }
 
 
@@ -575,6 +586,103 @@ def test_tile_map_partitions_real_rows(key):
         assert covered.all()
         for t in range(tm.n_tiles, tm.meta.shape[1]):    # inert capacity
             assert tm.meta[TILE_LO, t] == tm.meta[TILE_HI, t]
+
+
+def _tile_map(segments, T_pad, tile):
+    """The TileMap of back-to-back ``(start, n)`` segments, lane = index."""
+    offs = np.cumsum([0] + [n for _, n in segments[:-1]]).tolist()
+    return build_tile_map(offs, [n for _, n in segments],
+                          list(range(len(segments))),
+                          [start for start, _ in segments], T_pad,
+                          len(segments), tile)
+
+
+def _visible_blocks(tm, bs, window):
+    """Brute force: (tile, KV block) pairs in which some key is visible to
+    some row of the tile (causal, and inside the window when one is set)."""
+    n = 0
+    for t in range(tm.n_tiles):
+        lo, hi, pos0 = (int(tm.meta[r, t]) for r in (TILE_LO, TILE_HI,
+                                                     TILE_POS0))
+        blocks = set()
+        for p in range(pos0, pos0 + hi - lo):
+            first = max(0, p - window + 1) if window else 0
+            blocks.update(k // bs for k in range(first, p + 1))
+        n += len(blocks)
+    return n
+
+
+@pytest.mark.parametrize("window", [0, 3, 5, 9])
+@pytest.mark.parametrize("mix", sorted(TILED_MIXES))
+def test_tile_map_kv_blocks_counts_visible_blocks(mix, window):
+    """``TileMap.kv_blocks`` — the tiled kernel's live (tile, block)
+    visits — is exactly the brute-force count of pairs holding a key some
+    row of the tile may see, for every mix, block size and window."""
+    tile, segments, T_pad = TILED_MIXES[mix]
+    tm = _tile_map(segments, T_pad, tile)
+    for bs in (1, 4, 8):
+        assert tm.kv_blocks(bs, window) == _visible_blocks(tm, bs, window)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def test_tiled_kernel_skips_blocks_below_the_window(key, quantized):
+    """A window shorter than the context leaves each tile's first blocks
+    dead (the loop starts at j_lo > 0): the kernel still matches the
+    reference, and never reads those blocks — poisoning them with NaN (the
+    K/V slabs, or an int8 pool's scale rows) changes nothing."""
+    tile, bs, max_blocks, window = 8, 4, 8, 5
+    segments = [(29, 1), (22, 3), (30, 2), (3, 1)]
+    Hkv, G, D = 2, 4, 32
+    q, kp, vp, tables, ttab, tpos, tm, T = _tiled_setup(
+        key, segments, Hkv, G, D, bs, max_blocks, tile, 8, jnp.float32)
+    assert tm.kv_blocks(bs, window) < tm.kv_blocks(bs)
+    sc = {}
+    if quantized:
+        kp, vp, sc = _quantized_pools(jax.random.fold_in(key, 1), kp.shape)
+    ref = paged_attention_ragged_tiled_reference(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(tm.meta),
+        jnp.asarray(tm.row_tile), tile=tile, window=window, **sc)[:T]
+    dead = [tables[lane, j] for lane, (start, _) in enumerate(segments)
+            for j in range(max(start - window + 1, 0) // bs)]
+    assert dead
+    if quantized:
+        sc = {n: a.at[jnp.asarray(dead)].set(jnp.nan) for n, a in sc.items()}
+    else:
+        kp = kp.at[jnp.asarray(dead)].set(jnp.nan)
+        vp = vp.at[jnp.asarray(dead)].set(jnp.nan)
+    out = paged_attention_ragged_tiled(
+        q.reshape(8, Hkv, G, D), kp, vp, jnp.asarray(tables),
+        jnp.asarray(tm.meta), jnp.asarray(tm.row_tile), tile=tile,
+        window=window, interpret=True, **sc).reshape(8, Hkv * G, D)[:T]
+    assert np.all(np.isfinite(np.asarray(out)))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_tiled_kernel_matches_the_old_grids_maths_at_serving_widths(key):
+    """The reference computes what the old (tile, kv_head, block) grid
+    did — every block of the lane, each head on its own; the kernel walks
+    only the live blocks with all heads per block.  Same inputs, same
+    rows within the tolerance of the other tiled tests, at a served head
+    width (D = 128, block 16): decode lanes of many depths beside two
+    prefill chunks that straddle windows, inert capacity tiles beside."""
+    tile, bs, max_blocks = 16, 16, 8
+    Hkv, G, D = 2, 4, 128
+    rng = np.random.default_rng(14)
+    segments = [(int(c), 1) for c in rng.integers(0, max_blocks * bs, 9)]
+    segments += [(0, 37), (61, 20)]
+    q, kp, vp, tables, ttab, tpos, tm, T = _tiled_setup(
+        key, segments, Hkv, G, D, bs, max_blocks, tile, 128, jnp.float32)
+    assert tm.n_tiles < tm.meta.shape[1]
+    ref = paged_attention_ragged_tiled_reference(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(tm.meta),
+        jnp.asarray(tm.row_tile), tile=tile)
+    out = paged_attention_ragged_tiled(
+        q.reshape(128, Hkv, G, D), kp, vp, jnp.asarray(tables),
+        jnp.asarray(tm.meta), jnp.asarray(tm.row_tile), tile=tile,
+        interpret=True).reshape(128, Hkv * G, D)
+    np.testing.assert_allclose(np.asarray(out[:T]), np.asarray(ref[:T]),
+                               atol=2e-5, rtol=2e-5)
 
 
 # ---------------------------------------------------------------------------

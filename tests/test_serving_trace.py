@@ -7,7 +7,9 @@ CPU profiler trace; the ``.xplane.pb`` is read back with
   * one ``serve.step`` per engine step, ``step_num`` consecutive;
   * every other ``serve.*`` span nests inside a ``serve.step``;
   * one ``serve.engine.dispatch`` per step, its args those of the step's
-    :class:`~repro.serving.scheduler.StepDecision`;
+    :class:`~repro.serving.scheduler.StepDecision` and, on the tiled
+    grid, the live (tile, KV block) visits of its
+    :class:`~repro.serving.batch.TileMap`;
   * ``t_submit <= t_admit <= t_first_token``, and a preempted request
     keeps the ``t_admit`` of its first admission;
   * greedy outputs are token-identical with the profiler on and off.
@@ -24,7 +26,7 @@ from repro.configs import get_config
 from repro.models import build_model
 from repro.serving import AsyncEngine, PagedDecodeEngine
 from repro.serving import trace
-from repro.serving.batch import padded_pow2
+from repro.serving.batch import RaggedBatch, padded_pow2
 
 KW = dict(n_slots=3, cache_len=64, block_size=4, chunk_tokens=8,
           cache_dtype=jnp.float32, compute_dtype=jnp.float32)
@@ -90,15 +92,28 @@ def _serve_spans(trace_dir):
 
 @pytest.fixture(scope="module")
 def traced(model, tmp_path_factory):
+    """The traced run: (engine, finished requests, each step's decision,
+    the ``serve.*`` spans, each step's TileMap)."""
     cfg, api, params = model
     eng = PagedDecodeEngine(api, params, **KW)
     trace_dir = str(tmp_path_factory.mktemp("serve-trace"))
-    done, decisions = _serve(eng, _prompts(cfg, 5), trace_dir)
-    return eng, done, decisions, _serve_spans(trace_dir)
+    tile_maps = []
+    tiles = RaggedBatch.tiles
+
+    def recording_tiles(batch, *a, **kw):
+        tile_maps.append(tiles(batch, *a, **kw))
+        return tile_maps[-1]
+
+    RaggedBatch.tiles = recording_tiles
+    try:
+        done, decisions = _serve(eng, _prompts(cfg, 5), trace_dir)
+    finally:
+        RaggedBatch.tiles = tiles
+    return eng, done, decisions, _serve_spans(trace_dir), tile_maps
 
 
 def test_one_step_span_per_engine_step(traced):
-    eng, _, decisions, spans = traced
+    eng, _, decisions, spans, _ = traced
     steps = sorted((s for s in spans if s[0] == trace.STEP),
                    key=lambda s: s[1])
     assert len(steps) == eng.steps == len(decisions) > 0
@@ -107,7 +122,7 @@ def test_one_step_span_per_engine_step(traced):
 
 
 def test_every_span_nests_inside_its_step(traced):
-    _, _, _, spans = traced
+    _, _, _, spans, _ = traced
     steps = [s for s in spans if s[0] == trace.STEP]
     names = {s[0] for s in spans}
     assert names == {trace.STEP, trace.SCHED, trace.SCHED_ADMIT,
@@ -121,26 +136,32 @@ def test_every_span_nests_inside_its_step(traced):
 
 
 def test_one_dispatch_per_step_with_the_decision_args(traced):
-    _, _, decisions, spans = traced
+    eng, _, decisions, spans, tile_maps = traced
     steps = sorted((s for s in spans if s[0] == trace.STEP),
                    key=lambda s: s[1])
     disp = [s for s in spans if s[0] == trace.DISPATCH]
     budget = KW["n_slots"] * KW["chunk_tokens"]
     assert any(d.n_prefill_tokens for d in decisions)
     assert any(not d.n_prefill_tokens for d in decisions)
-    for st, d in zip(steps, decisions):
+    assert len(tile_maps) == len(decisions)
+    for st, d, tm in zip(steps, decisions, tile_maps):
         inside = [s for s in disp if st[1] <= s[1] and s[2] <= st[2]]
         assert len(inside) == 1
         args = inside[0][3]
         real = sum(d.num_scheduled.values())
+        kv_blocks = tm.kv_blocks(KW["block_size"], eng.window)
+        assert 0 < kv_blocks <= tm.meta.shape[1] * eng.max_blocks
         assert args == {"tokens": padded_pow2(max(real, 1), budget),
                         "real_tokens": real,
                         "prefill_tokens": d.n_prefill_tokens,
-                        "decode_lanes": d.n_decode}
+                        "decode_lanes": d.n_decode,
+                        "kv_blocks": kv_blocks}
+    assert eng.stats()["kv_block_visits"] == sum(
+        tm.kv_blocks(KW["block_size"], eng.window) for tm in tile_maps)
 
 
 def test_admission_stamp_lies_between_submit_and_first_token(traced):
-    _, done, _, _ = traced
+    _, done, _, _, _ = traced
     for r in done:
         assert 0 < r.t_submit <= r.t_admit <= r.t_first_token, r.request_id
 
@@ -165,7 +186,7 @@ def test_a_preempted_request_keeps_its_first_admission(model):
 
 def test_outputs_identical_with_the_profiler_on_and_off(model, traced):
     cfg, api, params = model
-    _, on, _, _ = traced
+    _, on, _, _, _ = traced
     off, _ = _serve(PagedDecodeEngine(api, params, **KW), _prompts(cfg, 5))
     assert [r.generated for r in on] == [r.generated for r in off]
     assert all(len(r.generated) == MAX_NEW for r in on)
