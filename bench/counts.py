@@ -4,8 +4,11 @@ These count the work the algorithm requires, not what an implementation
 happens to do, so every implementation of a step is judged on the same
 work:
 
-* matmuls: 2 FLOPs per multiply-add for every non-embedding weight of
-  every layer, once per processed (real, not padding) token;
+* matmuls: 2 FLOPs per multiply-add for every non-embedding weight that
+  a token uses in each layer, once per processed (real, not padding)
+  token: attention, then a dense MLP, or in a mixture-of-experts layer
+  the router, the ``experts_per_token`` routed experts and the shared
+  experts (:func:`layer_matmul_params`);
 * LM head: ``2 * d_model * vocab`` only for rows whose logits are read —
   the rows that emit a token and the rows that verify a draft;
 * attention: each query token against its causal context clipped to the
@@ -19,7 +22,7 @@ n_read_rows)`` for every scheduled request.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Tuple
+from typing import Dict, Iterable, List, Tuple
 
 Segment = Tuple[int, int, int]          # (first position, tokens, rows read)
 
@@ -29,14 +32,43 @@ def head_dim(cfg: dict) -> int:
     return cfg.get("head_dim") or cfg["d_model"] // cfg["n_heads"]
 
 
-def layer_matmul_params(cfg: dict) -> int:
-    """Weights of one layer's matrix multiplications (no norms, biases or
-    embeddings)."""
-    d, H, Hkv, D = cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"], \
-        head_dim(cfg)
+def layer_matmul_params(cfg: dict) -> List[int]:
+    """For each layer of a configuration file, the weights of its matrix
+    multiplications that one token uses (no norms, biases or embeddings):
+
+    * attention: q and o over every query head, k and v over the kv heads;
+    * a dense MLP of ``d_ff`` (gated: three matrices, else two); in a
+      model with ``moe``, its leading ``first_dense_layers`` are dense of
+      ``dense_d_ff`` (``d_expert`` where that is 0);
+    * every other layer of a model with ``moe``: the router (``d_model x
+      n_experts``), ``experts_per_token`` routed experts of width
+      ``d_expert`` (always gated, three matrices) and ``n_shared_experts``
+      shared experts, one MLP of ``n_shared_experts x d_expert``.
+
+    These are the whole model's weights, however many chips share each
+    layer (a cell's peak is that of all its chips).  Expert parallelism,
+    a chip holding only some of the experts, has no count of its own:
+    the program has no such layer yet.  Defaults are the program's
+    (``mlp_type`` swiglu; no shared experts, no leading dense layers).
+    """
+    d, H, Hkv = cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"]
+    D = head_dim(cfg)
     attn = d * H * D * 2 + d * Hkv * D * 2            # q, o and k, v
-    mats = 3 if cfg["mlp_type"] in ("swiglu", "geglu") else 2
-    return attn + mats * d * cfg["d_ff"]
+    gated = cfg.get("mlp_type", "swiglu") in ("swiglu", "geglu")
+
+    def mlp(width: int) -> int:
+        return (3 if gated else 2) * d * width
+
+    moe = cfg.get("moe")
+    if moe is None:
+        return [attn + mlp(cfg["d_ff"])] * cfg["n_layers"]
+    n_dense = moe.get("first_dense_layers", 0)
+    d_expert = moe["d_expert"]
+    dense = attn + mlp(moe.get("dense_d_ff", 0) or d_expert)
+    sparse = (attn + d * moe["n_experts"]
+              + moe["experts_per_token"] * 3 * d * d_expert
+              + mlp(moe.get("n_shared_experts", 0) * d_expert))
+    return [dense] * n_dense + [sparse] * (cfg["n_layers"] - n_dense)
 
 
 def context_sum(first: int, n: int, window: int) -> int:
@@ -77,7 +109,7 @@ def step_counts(cfg: dict, segments: Iterable[Segment], *, window: int,
     return {
         "tokens": tokens,
         "rows_read": rows,
-        "matmul_flops": 2 * layer_matmul_params(cfg) * L * tokens,
+        "matmul_flops": 2 * sum(layer_matmul_params(cfg)) * tokens,
         "head_flops": 2 * cfg["d_model"] * cfg["vocab_size"] * rows,
         "attn_flops": attn_flops,
         "attn_bytes": attn_bytes,

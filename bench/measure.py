@@ -49,14 +49,15 @@ def step_work(rec: dict, steps) -> dict:
 
 def traced_mfu(rec: dict):
     """The traced steps' model FLOPs over the traced window at the bf16
-    peak, in percent (None without a trace or peaks)."""
+    peak of every chip the cell runs on, in percent (None without a trace
+    or peaks)."""
     tr, pk = rec.get("trace"), rec.get("peaks")
     if not tr or not pk or any(s[2] is None for s in rec["trace_steps"]) \
             or not rec["trace_steps"]:
         return None
     w = step_work(rec, rec["trace_steps"])
     flops = w["matmul_flops"] + w["head_flops"] + w["attn_flops"]
-    return 100.0 * flops / (tr["window_s"] * pk["bf16_flops"])
+    return 100.0 * flops / (tr["window_s"] * rec["chips"] * pk["bf16_flops"])
 
 
 def traced_attn_roofline(rec: dict):

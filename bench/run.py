@@ -5,14 +5,17 @@
 
 A cell names a model configuration (``bench/configs/<config>.json``) and
 a traffic mix (``bench/traffic/<mix>.json``); both are found by name, as
-are the metric readers (``bench/metrics/<metric>.py``).  One run is one
-process with one engine on one chip:
+are the metric readers (``bench/metrics/<metric>.py``).  The program's
+``ArchConfig`` takes every key of the configuration file that is not the
+harness's own (:func:`arch_config`).  One run is one process with one
+engine on the chips its cell asks for:
 
 1. set-up: weights made on the device from ``--seed`` by the
-   configuration's reference module, the program's ``DecodeEngine`` built
-   on them, every token bucket of its jitted step compiled, and the
-   traffic's ramp offered; ``setup_s`` runs from the start of this script
-   to the window's opening;
+   configuration's reference module (on several chips, made already
+   sharded over the program's ``(1, chips)`` serving mesh), the program's
+   ``DecodeEngine`` built on them (with that mesh), every token bucket of
+   its jitted step compiled, and the traffic's ramp offered; ``setup_s``
+   runs from the start of this script to the window's opening;
 2. the window: ``--seconds`` of the mix through ``AsyncEngine``, the
    program's streaming front end, with the profiler on when ``--trace 1``;
 3. the drain: load keeps arriving until every request due in the window
@@ -36,6 +39,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -44,6 +48,7 @@ import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import typing  # noqa: E402
 from typing import Callable, Dict, List, Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
@@ -130,6 +135,13 @@ def peaks_for(kind: str) -> dict:
     if kind not in table:
         raise BenchError(f"device {kind!r} is not in bench/peaks.json")
     return table[kind]
+
+
+def program_on_path() -> None:
+    """Make the serving program (``src/`` of this checkout) importable."""
+    src = os.path.join(ROOT, "src")
+    if src not in sys.path:
+        sys.path.insert(0, src)
 
 
 def seed_key(jax, seed: int):
@@ -248,14 +260,75 @@ def delta(a: dict, b: dict) -> dict:
 # ---------------------------------------------------------------------------
 # Set-up
 # ---------------------------------------------------------------------------
+# keys of a configuration file that the harness reads itself; every other
+# key is a field of the program's ArchConfig
+HARNESS_KEYS = frozenset((
+    "paper", "reference", "published", "reduced", "assumed", "deployment",
+    "weight_dtype", "compute", "engine", "correct", "norm_eps", "mesh"))
+
+
+def _build_dataclass(cls, values: dict, where: str):
+    """``cls(**values)``, a field whose type is a dataclass built from its
+    dict the same way; a key that is no field is a :class:`BenchError`."""
+    hints = typing.get_type_hints(cls)
+    names = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(values) - names)
+    if unknown:
+        raise BenchError(f"{where}: {unknown} name no field of the "
+                         f"program's {cls.__name__}")
+    kw = {}
+    for k, v in values.items():
+        sub = [t for t in typing.get_args(hints[k]) + (hints[k],)
+               if dataclasses.is_dataclass(t)]
+        if sub and isinstance(v, dict):
+            v = _build_dataclass(sub[0], v, f"{where}.{k}")
+        kw[k] = v
+    try:
+        return cls(**kw)
+    except TypeError as e:
+        raise BenchError(f"{where}: {e}") from e
+
+
 def arch_config(cfg: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
+    """The program's ``ArchConfig`` for a configuration file: every key
+    that is not the harness's own, sub-configurations (``moe``, ``ssm``,
+    ``xlstm``, ``frontend``) built from their dicts."""
+    program_on_path()
     from repro.configs.base import ArchConfig
-    keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff",
-            "vocab_size", "head_dim", "rope_theta", "sliding_window",
-            "mlp_type", "norm_type", "use_bias", "tie_embeddings")
-    return ArchConfig(name=cfg["name"], family=cfg["family"],
-                      source=cfg["source"], **{k: cfg[k] for k in keys})
+    model = {k: v for k, v in cfg.items() if k not in HARNESS_KEYS}
+    return _build_dataclass(ArchConfig, model, cfg.get("name", "config"))
+
+
+def serving_mesh(devices, cfg: dict):
+    """None for a cell on one chip; on several, the program's serving mesh
+    over them: ``(1, chips)`` on ``("data", "model")``, every layer
+    tensor-parallel.  The configuration's ``mesh`` names that degree."""
+    if len(devices) == 1:
+        return None
+    if cfg.get("mesh") != {"model": len(devices)}:
+        raise BenchError(f"a cell on {len(devices)} chips needs the "
+                         f"configuration's mesh to be {{'model': "
+                         f"{len(devices)}}}, not {cfg.get('mesh')}")
+    from jax.sharding import Mesh
+    return Mesh(np.array(devices).reshape(1, len(devices)),
+                ("data", "model"))
+
+
+def make_weights(jax, ref, cfg: dict, key, dtype, mesh):
+    """The reference module's weights from ``key``.  On a mesh they are
+    made already sharded as the program serves them
+    (``serving_param_specs``), so no device ever holds them whole."""
+    if mesh is None:
+        return ref.make_params(cfg, key, dtype)
+    from repro.launch import sharding as shlib
+    from repro.launch.mesh import mesh_axis_sizes
+
+    def make(k):
+        return ref.make_params(cfg, k, dtype)
+
+    specs = shlib.serving_param_specs(jax.eval_shape(make, key),
+                                      mesh_axis_sizes(mesh))
+    return jax.jit(make, out_shardings=shlib.to_named(specs, mesh))(key)
 
 
 def check_layout(jax, api, params) -> None:
@@ -502,7 +575,7 @@ class Bench:
 
     def __init__(self, cell: dict, seed: int, *, require_tpu: bool = True,
                  use_cache: bool = True, engine_hook=None):
-        sys.path.insert(0, os.path.join(ROOT, "src"))
+        program_on_path()
         try:
             import jax
             from repro.models import build_model
@@ -511,18 +584,16 @@ class Bench:
             raise BenchError(f"the serving program is not in this "
                              f"checkout ({e})") from e
         self.jax, self.cell, self.seed = jax, cell, seed
-        self.devices = jax.devices()
+        devices = jax.devices()
+        if require_tpu and devices[0].platform != "tpu":
+            raise BenchError(f"no TPU: JAX's first device is "
+                             f"{devices[0].platform}")
+        if len(devices) < cell["chips"]:
+            raise BenchError(f"the cell needs {cell['chips']} chips; "
+                             f"JAX sees {len(devices)}")
+        self.devices = devices[:cell["chips"]]
         self.dev = self.devices[0]
-        if require_tpu:
-            if self.dev.platform != "tpu":
-                raise BenchError(f"no TPU: JAX's first device is "
-                                 f"{self.dev.platform}")
-            if len(self.devices) < cell["chips"]:
-                raise BenchError(f"the cell needs {cell['chips']} chips; "
-                                 f"JAX sees {len(self.devices)}")
-            self.peaks = peaks_for(self.dev.device_kind)
-        else:
-            self.peaks = None
+        self.peaks = peaks_for(self.dev.device_kind) if require_tpu else None
         if use_cache:
             # the directory JAX_COMPILATION_CACHE_DIR names, where it is
             # set; otherwise a fixed one inside the checkout (the path is
@@ -541,18 +612,21 @@ class Bench:
         self.api = build_model(arch_config(cfg))
         dtype = {"float32": jax.numpy.float32,
                  "bfloat16": jax.numpy.bfloat16}[cfg["weight_dtype"]]
+        self.mesh = serving_mesh(self.devices, cfg)
         t = time.perf_counter()
-        self.params = self.ref.make_params(cfg, seed_key(jax, seed), dtype)
+        self.params = make_weights(jax, self.ref, cfg, seed_key(jax, seed),
+                                   dtype, self.mesh)
         jax.block_until_ready(self.params)
         check_layout(jax, self.api, self.params)
         log(f"weights: {sum(x.size for x in jax.tree.leaves(self.params))}"
-            f" {cfg['weight_dtype']} in {time.perf_counter() - t:.3f} s")
+            f" {cfg['weight_dtype']} in {time.perf_counter() - t:.3f} s"
+            f" on {len(self.devices)} device(s)")
         self.eng = DecodeEngine(
             self.api, self.params, n_slots=e["n_slots"],
             cache_len=e["cache_len"], block_size=e["block_size"],
             num_blocks=e["num_blocks"], chunk_tokens=e["chunk_tokens"],
             token_budget=e["token_budget"], draft_k=e["draft_k"],
-            window=cfg["sliding_window"])
+            window=cfg["sliding_window"], mesh=self.mesh)
         if engine_hook is not None:
             engine_hook(self.eng)
         t = time.perf_counter()
@@ -618,6 +692,7 @@ def drive(b: Bench, traffic, seconds: float, tracing: bool) -> dict:
     rec_ = {"cfg": b.cfg, "cell": b.cell["name"], "seconds": seconds,
             "run": run, "window": run["window_tracks"],
             "counters": counters, "steps": steps, "peaks": b.peaks,
+            "chips": len(b.devices),
             "trace": None, "trace_steps": [],
             "kv_bytes": 2, "act_bytes": 2, "offline": traffic.offline}
     if tracing:
@@ -707,10 +782,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     rec = drive(b, traffic, seconds, trace)
     rec["setup_s"] = rec["run"]["open"] - t_start
     attempted, failed = outcome(rec)
-    mem = b.dev.memory_stats() or {}
+    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in b.devices)
     device = {"platform": b.dev.platform, "kind": b.dev.device_kind,
-              "count": len(b.devices),
-              "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
+              "count": len(b.devices), "memory_peak_bytes": peak}
     wanted = cell["per_layer"] if trace else cell["end_to_end"]
     metrics = {}
     for m in wanted:

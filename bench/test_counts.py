@@ -6,10 +6,29 @@ CFG = {"n_layers": 2, "d_model": 8, "n_heads": 4, "n_kv_heads": 2,
 
 
 def test_layer_matmul_params_by_hand():
-    # q 8x4x2, o 4x2x8, k and v 8x2x2 each, up 8x16 and down 16x8
-    assert counts.layer_matmul_params(CFG) == 64 + 64 + 32 + 32 + 128 + 128
+    # q 8x4x2, o 4x2x8, k and v 8x2x2 each, up 8x16 and down 16x8; per
+    # layer
+    assert counts.layer_matmul_params(CFG) == \
+        [64 + 64 + 32 + 32 + 128 + 128] * 2
     gated = dict(CFG, mlp_type="swiglu")
-    assert counts.layer_matmul_params(gated) == 448 + 128
+    assert counts.layer_matmul_params(gated) == [448 + 128] * 2
+
+
+def test_moe_layer_matmul_params_by_hand():
+    # attention 192 a layer; 3 layers, the first dense (gelu, 8x12 and
+    # 12x8), then router 8x6, 2 of 6 routed experts of width 4 (gate, up,
+    # down: 3x8x4 each) and 2 shared of width 4 (one gelu MLP of 8: 8x8
+    # and 8x8)
+    moe = dict(CFG, n_layers=3, moe={"n_experts": 6, "experts_per_token": 2,
+                                     "d_expert": 4, "n_shared_experts": 2,
+                                     "first_dense_layers": 1,
+                                     "dense_d_ff": 12})
+    dense = 192 + 2 * 8 * 12
+    sparse = 192 + 48 + 2 * 3 * 8 * 4 + 2 * 8 * 8
+    assert counts.layer_matmul_params(moe) == [dense, sparse, sparse]
+    # dense_d_ff 0: the leading dense layers take d_expert
+    moe["moe"] = dict(moe["moe"], dense_d_ff=0)
+    assert counts.layer_matmul_params(moe)[0] == 192 + 2 * 8 * 4
 
 
 def test_context_sum_and_span_by_hand():
